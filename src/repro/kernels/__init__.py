@@ -1,3 +1,4 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas TPU kernels, one package each: kernel.py (the pallas_call) and
+# ref.py (a plain jax.numpy oracle).  Callers pass ``interpret``
+# explicitly; nothing here switches on the backend.  None of them is on
+# the served path yet (ROADMAP speed items 1, 2 and 5).

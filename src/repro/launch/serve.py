@@ -1,7 +1,10 @@
-"""Serving driver — disaggregated KVDirect service at CPU scale.
+"""Serving driver — disaggregated KVDirect service on this process's devices.
 
     PYTHONPATH=src python -m repro.launch.serve --arch deepseek-67b --smoke \
         --requests 4 --prompt-len 96 --max-new 8
+
+Workers are spread round robin over ``jax.devices()``.  ``chip_smoke.py``
+at the repository root is the one-chip bring-up run of this same path.
 
 Runs the REAL pipeline: prefill workers fill registered KV slabs, the
 decode worker pulls with one-sided reads through the transfer engine
@@ -21,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import use_compilation_cache
 from repro.models.registry import build_model
 from repro.obs import Tracer, all_request_breakdowns, mean_fractions
 from repro.serving.disagg import DisaggService
@@ -69,9 +73,11 @@ def main() -> None:
                          "drive routing (overrides --prefill-workers)")
     args = ap.parse_args()
 
+    use_compilation_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
+    # jitted: eager init draws each stacked weight in float32 first
+    params = jax.jit(model.init_params)(jax.random.PRNGKey(0))
     tracer = Tracer() if args.trace_out else None
     fleet = None
     if args.autoscale or args.preempt != "none" \

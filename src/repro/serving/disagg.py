@@ -42,6 +42,7 @@ import dataclasses
 import itertools
 import time
 
+import jax
 import numpy as np
 
 from repro.core.cluster import ClusterScheduler, MembershipEvent
@@ -155,6 +156,11 @@ class DisaggService:
                                      metrics=self.metrics)
         self._ids = itertools.count()
         self._wid_seq = {"p": itertools.count(), "d": itertools.count()}
+        # Worker k (prefill and decode alike, in creation order) computes
+        # on device k mod n of this process; each device holds one copy
+        # of the parameters, shared by the workers placed on it.
+        self._device_seq = itertools.count()
+        self._params_on: dict[jax.Device, dict] = {}
         self._next_base = 0x7F00_0000_0000  # bump allocator for KV slabs
         self.clock = 0.0
 
@@ -246,6 +252,15 @@ class DisaggService:
         return base
 
     # ------------------------------------------------------- membership
+    def _next_worker_params(self):
+        """The parameters the next worker computes with, committed to
+        its device (round robin over the process's devices)."""
+        devices = jax.devices()
+        device = devices[next(self._device_seq) % len(devices)]
+        if device not in self._params_on:
+            self._params_on[device] = jax.device_put(self.params, device)
+        return self._params_on[device]
+
     def _bind_topology(self, role: str, wid: str, num_blocks: int) -> int:
         """Topology-bound pool sizing: ``num_blocks`` is the reference
         (largest-VRAM) machine's pool; the bound machine gets a
@@ -264,7 +279,8 @@ class DisaggService:
     def add_prefill_worker(self, *, num_blocks: int = 256) -> str:
         wid = f"p{next(self._wid_seq['p'])}"  # monotonic: ids never reused
         num_blocks = self._bind_topology("prefill", wid, num_blocks)
-        w = PrefillWorker(_winfo(wid, "prefill"), self.model, self.params,
+        w = PrefillWorker(_winfo(wid, "prefill"), self.model,
+                          self._next_worker_params(),
                           num_blocks=num_blocks,
                           base_address=self._alloc_base(num_blocks),
                           quantize_transfer=self.quantize_transfer)
@@ -280,7 +296,8 @@ class DisaggService:
     def add_decode_worker(self, *, num_blocks: int = 256) -> str:
         wid = f"d{next(self._wid_seq['d'])}"
         num_blocks = self._bind_topology("decode", wid, num_blocks)
-        w = DecodeWorker(_winfo(wid, "decode"), self.model, self.params,
+        w = DecodeWorker(_winfo(wid, "decode"), self.model,
+                         self._next_worker_params(),
                          num_blocks=num_blocks, engine=self.engine,
                          base_address=self._alloc_base(num_blocks),
                          consume=self.consume,

@@ -7,14 +7,17 @@ KV through the transfer engine (pull_kv → one-sided reads + COMPLETE),
 reconstructs a device DecodeState from its own slab, and decodes with
 continuous batching.
 
-This is the CPU-scale end-to-end path (examples/serve_disaggregated.py);
-the pod-scale path is launch/serve.py + the sharded serve_step.  Both
-consume the same caches, descriptors, and engine.
+Each worker computes on the one device its parameters are committed to
+(``DisaggService`` gives every worker its own device, round robin), and
+puts the inputs it builds there.  The model runs as two jitted programs,
+``jit_prefill`` and ``jit_decode_step``, compiled once per shape and
+shared by every worker of the process.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import hashlib
 from typing import Sequence
 
@@ -31,7 +34,28 @@ from repro.serving.blocks import BlockPool, OutOfBlocks
 from repro.serving.kv_cache import PagedKVCache
 from repro.serving.request import Request, RequestState
 
-__all__ = ["PrefillWorker", "DecodeWorker", "SwappedKV"]
+__all__ = ["PrefillWorker", "DecodeWorker", "SwappedKV", "jit_prefill",
+           "jit_decode_step"]
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def jit_prefill(model, params, tokens):
+    """The served prefill: ``(logits [b, V], DecodeState)`` with no page
+    margin (the pages are parked in the slab, not decoded in place)."""
+    return model.prefill(params, {"tokens": tokens}, max_blocks_margin=0,
+                         remat=False)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def jit_decode_step(model, params, state, tokens):
+    """The served decode step: one token for every sequence of ``state``."""
+    return model.decode_step(params, state, tokens)
+
+
+def _device_of(params) -> jax.Device:
+    """The one device a worker computes on: where its parameters live."""
+    (device,) = jax.tree.leaves(params)[0].devices()
+    return device
 
 
 class PrefillWorker:
@@ -45,11 +69,12 @@ class PrefillWorker:
         cfg = model.cfg
         if not cfg.has_attention or cfg.sliding_window:
             raise NotImplementedError(
-                "CPU serving path covers paged-KV archs; SSM/SWA archs use "
+                "the served path covers paged-KV archs; SSM/SWA archs use "
                 "SlotCache transfer (see tests/test_pull_push.py)")
         self.info = info
         self.model = model
         self.params = params
+        self.device = _device_of(params)
         self.block_size = model.BLOCK_SIZE
         self.cache = PagedKVCache(
             info.worker_id,
@@ -108,10 +133,9 @@ class PrefillWorker:
         need = BlockPool.blocks_for_tokens(len(tokens), self.block_size)
         if not self.pool.can_allocate(need):
             raise OutOfBlocks(f"need {need} blocks: pool {self.pool.describe()}")
-        logits, state = self.model.prefill(
-            self.params, {"tokens": jnp.asarray(tokens[None], jnp.int32)},
-            max_blocks_margin=0, remat=False,
-        )
+        logits, state = jit_prefill(
+            self.model, self.params,
+            jax.device_put(np.asarray(tokens[None], np.int32), self.device))
         k_pages = np.asarray(state.k_pages[:, 0])  # [L, spb, bs, g, hd]
         v_pages = np.asarray(state.v_pages[:, 0])
         spb = k_pages.shape[1]
@@ -241,6 +265,7 @@ class DecodeWorker:
         self.info = info
         self.model = model
         self.params = params
+        self.device = _device_of(params)
         self.block_size = model.BLOCK_SIZE
         self.cache = PagedKVCache(
             info.worker_id,
@@ -520,7 +545,11 @@ class DecodeWorker:
         per_seq = max(self._pages_of(r) for r in batch) + margin_blocks
         tables = np.broadcast_to(
             np.arange(per_seq, dtype=np.int32)[None], (len(batch), per_seq))
-        return per_seq, jnp.asarray(tables)
+        return per_seq, self._put(tables)
+
+    def _put(self, x: np.ndarray) -> jax.Array:
+        """A host array, placed on this worker's device."""
+        return jax.device_put(x, self.device)
 
     def _build_state(self, batch: list[_Resident], margin_blocks: int) -> DecodeState:
         """Assemble a per-seq paged DecodeState from the residents' page
@@ -538,9 +567,10 @@ class DecodeWorker:
             k_pages[:, i, :n] = k
             v_pages[:, i, :n] = v
         return DecodeState(
-            context_lens=jnp.asarray([r.context_len for r in batch], jnp.int32),
-            k_pages=jnp.asarray(k_pages, jnp.bfloat16),
-            v_pages=jnp.asarray(v_pages, jnp.bfloat16),
+            context_lens=self._put(
+                np.asarray([r.context_len for r in batch], np.int32)),
+            k_pages=self._put(k_pages.astype(jnp.bfloat16)),
+            v_pages=self._put(v_pages.astype(jnp.bfloat16)),
             block_tables=tables,
         )
 
@@ -590,13 +620,15 @@ class DecodeWorker:
                     n = len(r.blocks)
                     k[i, :n] = kplane[r.blocks].astype(np.float32)
                     v[i, :n] = vplane[r.blocks].astype(np.float32)
-            return jnp.asarray(k, jnp.bfloat16), jnp.asarray(v, jnp.bfloat16)
+            return (self._put(k.astype(jnp.bfloat16)),
+                    self._put(v.astype(jnp.bfloat16)))
 
         state = DecodeState(
-            context_lens=jnp.asarray([r.context_len for r in batch], jnp.int32),
+            context_lens=self._put(
+                np.asarray([r.context_len for r in batch], np.int32)),
             block_tables=tables,
         )
-        tokens = jnp.asarray([r.last_token for r in batch], jnp.int32)
+        tokens = self._put(np.asarray([r.last_token for r in batch], np.int32))
         logits, state = self.model.decode_step_layerwise(
             self.params, state, tokens, fetch)
         # All layers landed; the pulls' COMPLETE tails resolve now.  A
@@ -730,11 +762,11 @@ class DecodeWorker:
             self._invalidate_step()
             batch = list(self.resident.values())
             state = self._build_state(batch, margin_blocks=self.step_margin_blocks)
-            tokens = jnp.asarray([r.last_token for r in batch], jnp.int32)
+            tokens = self._put(np.asarray([r.last_token for r in batch], np.int32))
             self._install_step(batch, state, tokens)
         batch = [self.resident[rid] for rid in self._step_ids]
-        logits, state = self.model.decode_step(
-            self.params, self._step_state, self._step_tokens)
+        logits, state = jit_decode_step(
+            self.model, self.params, self._step_state, self._step_tokens)
         if self.inflight:
             self.pump(pump_budget)  # transfer hides behind the step
         tokens = self._argmax_tokens(logits)
@@ -768,11 +800,11 @@ class DecodeWorker:
                     return {}
             batch = list(self.resident.values())
             state = self._build_state(batch, margin_blocks=self._round_margin(max_new))
-            tokens = jnp.asarray([r.last_token for r in batch], jnp.int32)
+            tokens = self._put(np.asarray([r.last_token for r in batch], np.int32))
             out = {r.req.request_id: [] for r in batch}
             steps_left = max_new
         for _ in range(steps_left):
-            logits, state = self.model.decode_step(self.params, state, tokens)
+            logits, state = jit_decode_step(self.model, self.params, state, tokens)
             if self.inflight:
                 self.pump(pump_budget)  # transfer hides behind the step
             tokens = self._argmax_tokens(logits)

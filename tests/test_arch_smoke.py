@@ -44,20 +44,6 @@ def built():
     return get
 
 
-# Known-failing on the CPU container since the seed: the train step
-# differentiates through an optimization_barrier the CPU lowering of this
-# jax version has no VJP rule for.  Keyed on backend so accelerator
-# runners still execute it; non-strict because some archs (whisper) take
-# a barrier-free path and pass even on CPU.
-cpu_train_step_xfail = pytest.mark.xfail(
-    jax.default_backend() == "cpu",
-    reason="optimization_barrier has no differentiation rule on the CPU "
-           "backend of this jax version (seed-known failure)",
-    strict=False,
-)
-
-
-@cpu_train_step_xfail
 @pytest.mark.parametrize("arch", ASSIGNED)
 def test_train_step_shapes_and_finite(arch, built):
     cfg, model, params = built(arch)
