@@ -1,0 +1,66 @@
+"""The plain reference agrees with the served programs at smoke sizes, for
+both architectures of the benchmark (SwiGLU with GQA, GELU with MQA)."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import smoke  # noqa: F401  (puts bench/ and src/ on the path)
+import reference
+import weights
+from repro.models.config import ModelConfig
+from repro.models.registry import build_model
+from repro.serving.engine import jit_decode_step, jit_prefill
+
+# The program computes in bfloat16 (bf16 activations and residual stream,
+# float32 accumulation) and the reference in float32 from the same bf16
+# weights.  At these sizes the program's logits lie within 0.07 of the
+# logits' standard deviation from the reference (0.069 SwiGLU/GQA, 0.038
+# GELU/MQA, measured); 0.1 leaves room for seeds, and an int8 (W8A8)
+# reference reads 0.15-0.18, outside it.
+TOL_OF_STD = 0.1
+
+
+def _model(arch):
+    s = smoke.MODELS[arch]
+    cfg = ModelConfig(name=arch, family="dense", num_layers=s["num_layers"],
+                      d_model=s["d_model"], vocab_size=s["vocab_size"],
+                      num_heads=s["num_heads"], num_kv_heads=s["num_kv_heads"],
+                      d_ff=s["d_ff"], head_dim=s["head_dim"], mlp_type=s["mlp"])
+    model = build_model(cfg)
+    params = weights.make(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)), 3)
+    return s, model, params
+
+
+@pytest.mark.parametrize("arch", ["yi", "granite"])
+def test_reference_matches_served_prefill_and_decode(arch):
+    s, model, params = _model(arch)
+    toks = np.random.default_rng(0).integers(0, s["vocab_size"], 72).astype(np.int32)
+    n = 60
+    V = s["vocab_size"]
+    logits, _ = jit_prefill(model, params, jnp.asarray(toks[None, :n]))
+    _, state = model.prefill(params, {"tokens": jnp.asarray(toks[None, :n])},
+                             max_blocks_margin=1, remat=False)
+    served = [np.asarray(logits[0, :V], np.float32)]
+    for t in toks[n:-1]:
+        logits, state = jit_decode_step(model, params, state, jnp.asarray([t]))
+        served.append(np.asarray(logits[0, :V], np.float32))
+    served = np.stack(served)
+    ref = reference.logits(params, s, toks[:-1], np.arange(n - 1, len(toks) - 1))
+    assert ref.shape == served.shape
+    err = np.abs(served - ref).max() / ref.std()
+    assert err < TOL_OF_STD, err
+
+
+@pytest.mark.parametrize("arch", ["yi", "granite"])
+def test_int8_control_departs_further_than_the_program(arch):
+    s, model, params = _model(arch)
+    toks = np.random.default_rng(1).integers(0, s["vocab_size"], 64).astype(np.int32)
+    rows = np.arange(32, 64)
+    logits, _ = jit_prefill(model, params, jnp.asarray(toks[None]))
+    ref = reference.logits(params, s, toks, rows)
+    ctl = reference.logits(params, s, toks, rows, "int8")
+    served = np.asarray(logits[0, : s["vocab_size"]], np.float32)
+    prog_err = np.abs(served - ref[-1]).max()
+    ctl_err = np.abs(ctl[-1] - ref[-1]).max()
+    assert ctl_err > 1.5 * prog_err, (ctl_err, prog_err)
