@@ -14,7 +14,8 @@ COMPLETE messages.  Two *modes* reproduce the paper's comparison:
 Two *backends* separate mechanism from timing:
 
 * ``memcpy``  — actually moves bytes between worker address spaces
-  (numpy views standing in for HBM); wall time is measured.  This is what
+  (numpy views standing in for HBM); with a tracer, each copy loop's
+  wall time is a ``transfer.copy`` span.  This is what
   the correctness tests and Fig. 15 measurements use.
 * ``timed``   — additionally accrues a modeled clock from ``LinkModel``
   (per-verb post overhead, RPC latency, kernel-launch/sync costs from the
@@ -28,7 +29,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Callable, Iterable, Sequence
 
 import numpy as np
@@ -125,8 +125,9 @@ class TransferStats:
     txns_submitted: int = 0         # original read transactions
     completes: int = 0
     modeled_time_s: float = 0.0     # LinkModel clock
-    wall_time_s: float = 0.0        # measured memcpy time
     rounds: int = 0                 # message-mode staging rounds
+    reads_executed: int = 0         # read transactions executed (not torn)
+    bytes_pulled: int = 0           # their logical bytes (before any codec)
 
     @property
     def coalesce_factor(self) -> float:
@@ -360,14 +361,16 @@ class TransferEngine:
         self.tick_budget = tick_budget
         self.stats = TransferStats()
         # Observability (optional; see docs/observability.md): the tracer
-        # records the per-request pull lifecycle — submit instant, one
-        # span per layer as its reads land, complete/torn instant — on
-        # the request's track, so a serve trace shows the wire timeline
-        # under the decode timeline.  The metrics registry accumulates
-        # engine totals (bytes, reads, completes, teardowns).
+        # records the per-request pull lifecycle — one span per layer as
+        # its reads land, complete/torn instant — on the request's track,
+        # so a serve trace shows the wire timeline under the decode
+        # timeline, and one ``transfer.copy`` span per executed window of
+        # reads on the engine's own track.  The metrics registry
+        # accumulates engine totals (bytes, reads, completes, teardowns).
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics = metrics
         self._layer_mark: dict[str, float] = {}  # rid -> last layer-end ts
+        self._track = ("engine", mode)
 
     # ------------------------------------------------------------- setup
     def register_memory(self, region: MemoryRegion) -> None:
@@ -445,10 +448,8 @@ class TransferEngine:
                 self._futures[t.request_id] = fut
                 created.append(fut)
                 if self.tracer.enabled:
-                    now = self.tracer.now()
-                    self._layer_mark[t.request_id] = now
-                    self.tracer.instant("transfer.submit", ts=now,
-                                        track=("request", t.request_id))
+                    # the first layer span starts at the submit
+                    self._layer_mark[t.request_id] = self.tracer.now()
                 if self.metrics is not None:
                     self.metrics.inc("engine.pulls_submitted")
             self._queue.append(t)
@@ -587,27 +588,36 @@ class TransferEngine:
         return healthy, first
 
     # --------------------------------------------------- tensor-centric
-    def _post_reads(self, window: Sequence[ReadTxn]) -> None:
-        healthy, torn_err = self._filter_torn(window)
+    def _count_pulled(self, healthy: Sequence[ReadTxn]) -> int:
+        """Charge the reads about to execute to their requests and the
+        engine totals; returns their logical bytes."""
+        nbytes = 0
         for t in healthy:
             self._pulled_bytes[t.request_id] += t.nbytes
+            nbytes += t.nbytes
+        self.stats.reads_executed += len(healthy)
+        self.stats.bytes_pulled += nbytes
+        return nbytes
+
+    def _post_reads(self, window: Sequence[ReadTxn]) -> None:
+        healthy, torn_err = self._filter_torn(window)
+        nbytes = self._count_pulled(healthy)
         merged = coalesce(healthy, strategy=self.coalescing)
-        t0 = time.perf_counter()
-        for op in merged:
-            self._copy(op)
-            self.stats.reads_posted += 1
-            quantized = self.codec != "none" or op.qscale is not None
-            wire = op.nbytes // 2 + 4 if quantized else op.nbytes
-            self.stats.bytes_moved += wire
-            self.stats.modeled_time_s += self.link.read_time(wire)
-        self.stats.wall_time_s += time.perf_counter() - t0
+        with self.tracer.span("transfer.copy", track=self._track,
+                              reads=len(healthy), bytes=nbytes):
+            for op in merged:
+                self._copy(op)
+                self.stats.reads_posted += 1
+                quantized = self.codec != "none" or op.qscale is not None
+                wire = op.nbytes // 2 + 4 if quantized else op.nbytes
+                self.stats.bytes_moved += wire
+                self.stats.modeled_time_s += self.link.read_time(wire)
         if self.metrics is not None and merged:
             self.metrics.inc("engine.reads_posted", len(merged))
             self.metrics.inc("engine.bytes_moved",
                              sum(op.nbytes for op in merged))
         if self.metrics is not None and healthy:
-            self.metrics.inc("engine.bytes_pulled",
-                             sum(t.nbytes for t in healthy))
+            self.metrics.inc("engine.bytes_pulled", nbytes)
         # torn reads are accounted too — consumed (future already failed),
         # not executed — so a queued COMPLETE for them stays inert instead
         # of raising "reads still queued"
@@ -620,38 +630,39 @@ class TransferEngine:
         """Fig. 7a: bounded staging buffer, per-round RPC + gather + send +
         scatter + notify, with REAL double copies under memcpy."""
         healthy, torn_err = self._filter_torn(window)
-        for t in healthy:
-            self._pulled_bytes[t.request_id] += t.nbytes
-        t0 = time.perf_counter()
-        round_txns: list[ReadTxn] = []
-        round_bytes = 0
-        for t in list(healthy) + [None]:  # type: ignore[list-item]
-            flush = t is None or (round_bytes + t.nbytes > self.staging_bytes and round_txns)
-            if flush and round_txns:
-                staging = np.empty(round_bytes, dtype=np.uint8) if self.execute_copies else None
-                off = 0
-                for rt in round_txns:  # gather (copy #1)
-                    if staging is not None:
-                        staging[off : off + rt.nbytes] = self._src_view(rt)
-                    off += rt.nbytes
-                off = 0
-                for rt in round_txns:  # scatter (copy #2)
-                    if staging is not None:
-                        self._dst_view(rt)[...] = staging[off : off + rt.nbytes]
-                    off += rt.nbytes
-                self.stats.rounds += 1
-                self.stats.reads_posted += 1
-                self.stats.bytes_moved += round_bytes
-                self.stats.modeled_time_s += self.link.message_stream_time(
-                    round_bytes, len(round_txns))
-                if self.metrics is not None:
-                    self.metrics.inc("engine.reads_posted")
-                    self.metrics.inc("engine.bytes_moved", round_bytes)
-                round_txns, round_bytes = [], 0
-            if t is not None:
-                round_txns.append(t)
-                round_bytes += t.nbytes
-        self.stats.wall_time_s += time.perf_counter() - t0
+        nbytes = self._count_pulled(healthy)
+        with self.tracer.span("transfer.copy", track=self._track,
+                              reads=len(healthy), bytes=nbytes):
+            round_txns: list[ReadTxn] = []
+            round_bytes = 0
+            for t in list(healthy) + [None]:  # type: ignore[list-item]
+                flush = t is None or (round_bytes + t.nbytes > self.staging_bytes
+                                      and round_txns)
+                if flush and round_txns:
+                    staging = (np.empty(round_bytes, dtype=np.uint8)
+                               if self.execute_copies else None)
+                    off = 0
+                    for rt in round_txns:  # gather (copy #1)
+                        if staging is not None:
+                            staging[off : off + rt.nbytes] = self._src_view(rt)
+                        off += rt.nbytes
+                    off = 0
+                    for rt in round_txns:  # scatter (copy #2)
+                        if staging is not None:
+                            self._dst_view(rt)[...] = staging[off : off + rt.nbytes]
+                        off += rt.nbytes
+                    self.stats.rounds += 1
+                    self.stats.reads_posted += 1
+                    self.stats.bytes_moved += round_bytes
+                    self.stats.modeled_time_s += self.link.message_stream_time(
+                        round_bytes, len(round_txns))
+                    if self.metrics is not None:
+                        self.metrics.inc("engine.reads_posted")
+                        self.metrics.inc("engine.bytes_moved", round_bytes)
+                    round_txns, round_bytes = [], 0
+                if t is not None:
+                    round_txns.append(t)
+                    round_bytes += t.nbytes
         self._account_executed(window)
         if torn_err is not None:
             raise torn_err

@@ -137,11 +137,9 @@ def main() -> None:
     print(f"[serve] {args.requests} requests in {time.perf_counter()-t0:.1f}s; "
           f"transfer modeled {svc.engine.stats.modeled_time_s*1e3:.2f} ms total")
     # the serve-path counters/histograms, from the one registry every
-    # layer (loop, engine, router, request completion) reports into
+    # layer (loop, engine, router, workers, request completion) reports into
     print("[serve] metrics:")
-    for line in svc.metrics.format(
-            prefixes=("requests.", "request.", "engine.", "loop.",
-                      "fleet.")).splitlines():
+    for line in svc.metrics.format().splitlines():
         print(f"[serve]   {line}")
     if tracer is not None:
         breakdowns = all_request_breakdowns(tracer)

@@ -233,7 +233,10 @@ class Tracer:
         Tracks map to named threads of one process; timestamps are
         microseconds relative to the earliest recorded event, so sim
         (virtual-seconds) and real (perf_counter) traces render the
-        same way."""
+        same way.  ``otherData.clock_base_s`` is that event's time on
+        the tracer's clock, so an event's clock time is
+        ``clock_base_s + ts / 1e6`` (docs/observability.md: lining a
+        trace up with a JAX profile)."""
         events: list[dict] = []
         all_spans: Iterable[Span] = [*self.spans, *self.instants]
         t_base = min((s.t0 for s in all_spans), default=0.0)
@@ -264,7 +267,8 @@ class Tracer:
                 "cat": track_name(s.track),
                 "args": {k: _jsonable(v) for k, v in s.attrs.items()},
             })
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
+        return {"traceEvents": events, "displayTimeUnit": "ms",
+                "otherData": {"clock_base_s": t_base}}
 
     def export_chrome(self, path: str, **kw) -> dict:
         """Write the Chrome trace JSON to ``path``; returns the object."""

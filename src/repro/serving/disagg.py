@@ -54,6 +54,7 @@ from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER
 from repro.sched import LoadReport, NoWorkersError, RequestRouter, RouteRequest
 from repro.serving.blocks import OutOfBlocks
+from repro.serving.compiles import record_compiles
 from repro.serving.engine import DecodeWorker, PrefillWorker
 from repro.serving.handle import RequestHandle
 from repro.serving.kv_cache import PagedKVCache
@@ -125,8 +126,9 @@ class DisaggService:
         with nothing resident behaves exactly as before.
 
         Observability (docs/observability.md): pass a ``repro.obs.Tracer``
-        as ``tracer`` to record per-request lifecycle spans and loop/engine
-        phase spans (the default is the disabled no-op tracer); ``metrics``
+        as ``tracer`` to record per-request lifecycle spans, loop, worker
+        and engine spans, and ``jax.compile`` spans (the default is the
+        disabled no-op tracer, which registers nothing); ``metrics``
         is the ``MetricsRegistry`` serve-path counters/histograms land in
         (one is created when omitted); ``clock`` is THE wall clock for
         every observability timestamp — tracer spans, handle metrics, and
@@ -151,6 +153,8 @@ class DisaggService:
         if tracer is not None and clock is not None:
             tracer.clock = clock  # one clock: spans == handle metrics
         self.metrics = metrics if metrics is not None else MetricsRegistry()
+        # ``jax.compile`` spans on the tracer (None when it is disabled)
+        self.compile_spans = record_compiles(self.tracer)
         self.scheduler = ClusterScheduler()
         self.engine = TransferEngine(coalescing="sorted", tracer=self.tracer,
                                      metrics=self.metrics)
@@ -283,7 +287,8 @@ class DisaggService:
                           self._next_worker_params(),
                           num_blocks=num_blocks,
                           base_address=self._alloc_base(num_blocks),
-                          quantize_transfer=self.quantize_transfer)
+                          quantize_transfer=self.quantize_transfer,
+                          tracer=self.tracer)
         self.prefills[wid] = w
         self.engine.register_memory(w.cache.memory_region())
         # seed liveness at the CURRENT clock, else a worker added late is

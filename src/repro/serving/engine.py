@@ -61,11 +61,13 @@ def _device_of(params) -> jax.Device:
 class PrefillWorker:
     def __init__(self, info: WorkerInfo, model, params, *, num_blocks: int = 256,
                  base_address: int = 0x7F06F40000,
-                 quantize_transfer: bool = False):
+                 quantize_transfer: bool = False,
+                 tracer=None):
         """``quantize_transfer``: compute per-(layer, block, plane) int8
         scales at park time so decode-side pulls move quantized wire
         bytes with the scale carried in each ``ReadTxn`` descriptor
-        (docs/transfer.md § quantized transfer)."""
+        (docs/transfer.md § quantized transfer).  ``tracer`` records the
+        ``prefill.*`` spans on the worker's track."""
         cfg = model.cfg
         if not cfg.has_attention or cfg.sliding_window:
             raise NotImplementedError(
@@ -75,6 +77,8 @@ class PrefillWorker:
         self.model = model
         self.params = params
         self.device = _device_of(params)
+        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self._track = ("worker", info.worker_id)
         self.block_size = model.BLOCK_SIZE
         self.cache = PagedKVCache(
             info.worker_id,
@@ -133,19 +137,28 @@ class PrefillWorker:
         need = BlockPool.blocks_for_tokens(len(tokens), self.block_size)
         if not self.pool.can_allocate(need):
             raise OutOfBlocks(f"need {need} blocks: pool {self.pool.describe()}")
-        logits, state = jit_prefill(
-            self.model, self.params,
-            jax.device_put(np.asarray(tokens[None], np.int32), self.device))
-        k_pages = np.asarray(state.k_pages[:, 0])  # [L, spb, bs, g, hd]
-        v_pages = np.asarray(state.v_pages[:, 0])
+        # device time, then the device-to-host fetch of the pages
+        with self.tracer.span("prefill.compute", track=self._track,
+                              tokens=len(tokens)) as s:
+            logits, state = jit_prefill(
+                self.model, self.params,
+                jax.device_put(np.asarray(tokens[None], np.int32), self.device))
+            k_pages = np.asarray(state.k_pages[:, 0])  # [L, spb, bs, g, hd]
+            v_pages = np.asarray(state.v_pages[:, 0])
+            first = int(jnp.argmax(logits[0, : self.model.cfg.vocab_size]))
+            s.set(bytes=k_pages.nbytes + v_pages.nbytes)
         spb = k_pages.shape[1]
-        blocks = self.pool.allocate(spb)
-        for layer in range(self.cache.num_layers):
-            for j, blk in enumerate(blocks):
-                self.cache.write_block(layer, blk, k_pages[layer, j], v_pages[layer, j])
-        first = int(jnp.argmax(logits[0, : self.model.cfg.vocab_size]))
-        hashes = self._digest_blocks(blocks)
-        scales = self._quant_scales(blocks) if self.quantize_transfer else None
+        with self.tracer.span("prefill.park", track=self._track, blocks=spb):
+            blocks = self.pool.allocate(spb)
+            for layer in range(self.cache.num_layers):
+                for j, blk in enumerate(blocks):
+                    self.cache.write_block(layer, blk, k_pages[layer, j], v_pages[layer, j])
+        with self.tracer.span("prefill.hash", track=self._track, blocks=spb):
+            hashes = self._digest_blocks(blocks)
+        scales = None
+        if self.quantize_transfer:
+            with self.tracer.span("prefill.quant", track=self._track, blocks=spb):
+                scales = self._quant_scales(blocks)
         return first, blocks, hashes, scales
 
     def prefill(self, req: Request, tokens: np.ndarray) -> int:
@@ -260,6 +273,7 @@ class DecodeWorker:
         self.consume = consume
         self.delta_transfer = delta_transfer
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        self._track = ("worker", info.worker_id)
         self.metrics = metrics
         cfg = model.cfg
         self.info = info
@@ -401,10 +415,6 @@ class DecodeWorker:
         self.tracer.phase(("request", req.request_id), "transfer",
                           worker=self.info.worker_id, blocks=len(blocks),
                           reused_blocks=len(grafted))
-        if self.metrics is not None:
-            self.metrics.inc("decode.admitted")
-            if grafted:
-                self.metrics.inc("decode.blocks_grafted", len(grafted))
         return fut
 
     def admit_batch(
@@ -557,22 +567,27 @@ class DecodeWorker:
         cfg = self.model.cfg
         bs = self.block_size
         L = cfg.num_layers
-        per_seq, tables = self._batch_tables(batch, margin_blocks)
-        b = len(batch)
-        k_pages = np.zeros((L, b, per_seq, bs, cfg.num_kv_heads, cfg.head_dim), np.float32)
-        v_pages = np.zeros_like(k_pages)
-        for i, r in enumerate(batch):
-            k, v = self._resident_pages(r)
-            n = k.shape[1]
-            k_pages[:, i, :n] = k
-            v_pages[:, i, :n] = v
-        return DecodeState(
-            context_lens=self._put(
-                np.asarray([r.context_len for r in batch], np.int32)),
-            k_pages=self._put(k_pages.astype(jnp.bfloat16)),
-            v_pages=self._put(v_pages.astype(jnp.bfloat16)),
-            block_tables=tables,
-        )
+        with self.tracer.span("step.build", track=self._track) as s:
+            per_seq, tables = self._batch_tables(batch, margin_blocks)
+            b = len(batch)
+            k_pages = np.zeros((L, b, per_seq, bs, cfg.num_kv_heads, cfg.head_dim),
+                               np.float32)
+            v_pages = np.zeros_like(k_pages)
+            for i, r in enumerate(batch):
+                k, v = self._resident_pages(r)
+                n = k.shape[1]
+                k_pages[:, i, :n] = k
+                v_pages[:, i, :n] = v
+            ctx = np.asarray([r.context_len for r in batch], np.int32)
+            # host-to-device: both bf16 planes, the lengths, the tables
+            s.set(bytes=2 * k_pages.size * np.dtype(jnp.bfloat16).itemsize
+                  + ctx.nbytes + tables.nbytes)
+            return DecodeState(
+                context_lens=self._put(ctx),
+                k_pages=self._put(k_pages.astype(jnp.bfloat16)),
+                v_pages=self._put(v_pages.astype(jnp.bfloat16)),
+                block_tables=tables,
+            )
 
     def _argmax_tokens(self, logits) -> jnp.ndarray:
         return jnp.argmax(
@@ -629,8 +644,10 @@ class DecodeWorker:
             block_tables=tables,
         )
         tokens = self._put(np.asarray([r.last_token for r in batch], np.int32))
-        logits, state = self.model.decode_step_layerwise(
-            self.params, state, tokens, fetch)
+        # each layer's fetch pumps the engine: its copies nest in here
+        with self.tracer.span("step.launch", track=self._track, layerwise=True):
+            logits, state = self.model.decode_step_layerwise(
+                self.params, state, tokens, fetch)
         # All layers landed; the pulls' COMPLETE tails resolve now.  A
         # failure here (torn after the last layer, COMPLETE swallowed)
         # invalidates the admission exactly like a mid-layer tear.
@@ -643,15 +660,16 @@ class DecodeWorker:
         for fl in streaming:
             if fl.future.failed:
                 raise fl.future.exception()
-        self.pump(0)  # promote the resolved admissions (no transfer work)
+        self._step_pump(0, overlapped=False)  # promote the resolved admissions
         for r in batch[len(residents):]:
             # keep OUR entry: it reflects the step this round already ran
             self.resident[r.req.request_id] = r
-        tokens = self._argmax_tokens(logits)
-        out: dict[str, list[int]] = {r.req.request_id: [] for r in batch}
-        for i, r in enumerate(batch):
-            out[r.req.request_id].append(int(tokens[i]))
-            r.req.tokens_generated += 1
+        with self.tracer.span("step.commit", track=self._track):
+            tokens = self._argmax_tokens(logits)
+            out: dict[str, list[int]] = {r.req.request_id: [] for r in batch}
+            for i, r in enumerate(batch):
+                out[r.req.request_id].append(int(tokens[i]))
+                r.req.tokens_generated += 1
         return batch, state, tokens, out
 
     def _streaming_step(self, margin_blocks: int, pump_budget: int | None):
@@ -693,16 +711,18 @@ class DecodeWorker:
         ids, self._step_ids = self._step_ids, []
         self._step_state = self._step_tokens = None
         self._step_per_seq = 0
-        k_all = np.asarray(state.k_pages).astype(np.float32)
-        v_all = np.asarray(state.v_pages).astype(np.float32)
-        for i, rid in enumerate(ids):
-            r = self.resident.get(rid)
-            if r is None:
-                continue  # finished / aborted while the state was live
-            pages = -(-r.context_len // self.block_size)
-            r.k_cached = np.ascontiguousarray(k_all[:, i, :pages])
-            r.v_cached = np.ascontiguousarray(v_all[:, i, :pages])
-            r.cached_from = tuple(r.blocks)  # writeback covers all blocks
+        with self.tracer.span("step.writeback", track=self._track,
+                              bytes=state.k_pages.nbytes + state.v_pages.nbytes):
+            k_all = np.asarray(state.k_pages).astype(np.float32)
+            v_all = np.asarray(state.v_pages).astype(np.float32)
+            for i, rid in enumerate(ids):
+                r = self.resident.get(rid)
+                if r is None:
+                    continue  # finished / aborted while the state was live
+                pages = -(-r.context_len // self.block_size)
+                r.k_cached = np.ascontiguousarray(k_all[:, i, :pages])
+                r.v_cached = np.ascontiguousarray(v_all[:, i, :pages])
+                r.cached_from = tuple(r.blocks)  # writeback covers all blocks
 
     def _commit_step(self, batch: list[_Resident], state: DecodeState,
                      tokens: jnp.ndarray) -> dict[str, int]:
@@ -742,16 +762,17 @@ class DecodeWorker:
                 # on the very next step (another join, a leave, margin)
                 # writes back and restarts from these fields — stale
                 # values would replay the token and drop an appended page
-                ctx = np.asarray(state.context_lens)
-                for i, r in enumerate(batch):
-                    r.context_len = int(ctx[i])
-                    r.last_token = int(tokens[i])
+                with self.tracer.span("step.commit", track=self._track):
+                    ctx = np.asarray(state.context_lens)
+                    for i, r in enumerate(batch):
+                        r.context_len = int(ctx[i])
+                        r.last_token = int(tokens[i])
                 self._install_step(batch, state, tokens)
                 return {rid: toks[0] for rid, toks in out.items()}
         else:
             # promote pulls that resolved since the last step (and nudge
             # the engine while there is in-flight work to hide)
-            self.pump(pump_budget if self.inflight else 0)
+            self._step_pump(pump_budget if self.inflight else 0, overlapped=False)
         if not self.resident:
             return {}
         ids = list(self.resident)
@@ -759,20 +780,42 @@ class DecodeWorker:
             r.context_len >= self._step_per_seq * self.block_size
             for r in self.resident.values())
         if ids != self._step_ids or exhausted:
-            self._invalidate_step()
+            if ids == self._step_ids:
+                reason = "margin"
+            else:
+                reason = "join" if set(ids) - set(self._step_ids) else "leave"
             batch = list(self.resident.values())
-            state = self._build_state(batch, margin_blocks=self.step_margin_blocks)
-            tokens = self._put(np.asarray([r.last_token for r in batch], np.int32))
-            self._install_step(batch, state, tokens)
+            with self.tracer.span("step.rebuild", track=self._track,
+                                  batch=len(batch), reason=reason) as s:
+                self._invalidate_step()
+                state = self._build_state(batch, margin_blocks=self.step_margin_blocks)
+                tokens = self._put(np.asarray([r.last_token for r in batch], np.int32))
+                self._install_step(batch, state, tokens)
+                s.set(per_seq=self._step_per_seq)
         batch = [self.resident[rid] for rid in self._step_ids]
-        logits, state = jit_decode_step(
-            self.model, self.params, self._step_state, self._step_tokens)
+        # dispatch only: the call is asynchronous
+        with self.tracer.span("step.launch", track=self._track):
+            logits, state = jit_decode_step(
+                self.model, self.params, self._step_state, self._step_tokens)
         if self.inflight:
-            self.pump(pump_budget)  # transfer hides behind the step
-        tokens = self._argmax_tokens(logits)
-        out = self._commit_step(batch, state, tokens)
+            self._step_pump(pump_budget, overlapped=True)  # hides behind the step
+        # the host waits for the chip here, and reads the tokens
+        with self.tracer.span("step.commit", track=self._track):
+            tokens = self._argmax_tokens(logits)
+            out = self._commit_step(batch, state, tokens)
         self._step_state, self._step_tokens = state, tokens
         return out
+
+    def _step_pump(self, budget: int | None, *, overlapped: bool) -> list[str]:
+        """``pump`` inside a ``step.pump`` span carrying the read
+        transactions and logical bytes it executed."""
+        st = self.engine.stats
+        reads, nbytes = st.reads_executed, st.bytes_pulled
+        with self.tracer.span("step.pump", track=self._track,
+                              overlapped=overlapped) as s:
+            promoted = self.pump(budget)
+            s.set(reads=st.reads_executed - reads, bytes=st.bytes_pulled - nbytes)
+        return promoted
 
     def decode_round(self, max_new: int = 8, *,
                      pump_budget: int | None = 32) -> dict[str, list[int]]:
