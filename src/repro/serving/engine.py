@@ -594,15 +594,32 @@ class DecodeWorker:
             logits[:, : self.model.cfg.vocab_size].astype(jnp.float32), axis=-1
         ).astype(jnp.int32)
 
+    def _commit(self, batch: list[_Resident], logits) -> tuple[jax.Array, list[int]]:
+        """Record one step's tokens on ``batch`` with ONE device-to-host
+        read; returns the device tokens (the next step's input) and the
+        host tokens, in batch order.  ``context_len`` is counted on the
+        host: the step adds one to every member's device length, and a
+        rebuild seeds the device lengths from this count."""
+        with self.tracer.span("step.commit", track=self._track, reads=1):
+            tokens = self._argmax_tokens(logits)
+            tokens.copy_to_host_async()  # starts as soon as the step ends
+            host = np.asarray(tokens).tolist()  # the host waits for the chip here
+            for r, tok in zip(batch, host):
+                r.req.tokens_generated += 1
+                r.context_len += 1
+                r.last_token = tok
+        return tokens, host
+
     # ----------------------------------------- layerwise first step
     def _layerwise_first_step(self, streaming: list[_InFlight],
                               margin_blocks: int, pump_budget: int | None):
         """One decode step where ``streaming`` (in-flight) admissions join
         the resident batch, consuming each layer's KV as its reads land
         (``wait_layer`` pumps the engine between layers).  Returns
-        ``(batch, state, tokens, out)`` with the first round token already
-        recorded; raises ``ConnectionTornError`` if any streaming pull
-        dies mid-step (the caller retries without it)."""
+        ``(batch, state, tokens, host)`` with the step already committed
+        (``host``: its tokens, in batch order); raises
+        ``ConnectionTornError`` if any streaming pull dies mid-step (the
+        caller retries without it)."""
         cfg = self.model.cfg
         bs = self.block_size
         residents = list(self.resident.values())
@@ -664,13 +681,8 @@ class DecodeWorker:
         for r in batch[len(residents):]:
             # keep OUR entry: it reflects the step this round already ran
             self.resident[r.req.request_id] = r
-        with self.tracer.span("step.commit", track=self._track):
-            tokens = self._argmax_tokens(logits)
-            out: dict[str, list[int]] = {r.req.request_id: [] for r in batch}
-            for i, r in enumerate(batch):
-                out[r.req.request_id].append(int(tokens[i]))
-                r.req.tokens_generated += 1
-        return batch, state, tokens, out
+        tokens, host = self._commit(batch, logits)
+        return batch, state, tokens, host
 
     def _streaming_step(self, margin_blocks: int, pump_budget: int | None):
         """Run the layerwise first step over every in-flight admission,
@@ -724,20 +736,6 @@ class DecodeWorker:
                 r.v_cached = np.ascontiguousarray(v_all[:, i, :pages])
                 r.cached_from = tuple(r.blocks)  # writeback covers all blocks
 
-    def _commit_step(self, batch: list[_Resident], state: DecodeState,
-                     tokens: jnp.ndarray) -> dict[str, int]:
-        """Record one step's outputs on the residents; returns
-        request_id -> token."""
-        ctx = np.asarray(state.context_lens)
-        out: dict[str, int] = {}
-        for i, r in enumerate(batch):
-            tok = int(tokens[i])
-            out[r.req.request_id] = tok
-            r.req.tokens_generated += 1
-            r.context_len = int(ctx[i])
-            r.last_token = tok
-        return out
-
     # ------------------------------------------------- continuous stepping
     def step(self, *, pump_budget: int | None = 32) -> dict[str, int]:
         """ONE continuous-batching decode step: every resident advances by
@@ -757,18 +755,9 @@ class DecodeWorker:
             self._invalidate_step()  # caches must be current to co-batch
             stream = self._streaming_step(self.step_margin_blocks, pump_budget)
             if stream is not None:
-                batch, state, tokens, out = stream
-                # commit the step's context_len/last_token NOW: a rebuild
-                # on the very next step (another join, a leave, margin)
-                # writes back and restarts from these fields — stale
-                # values would replay the token and drop an appended page
-                with self.tracer.span("step.commit", track=self._track):
-                    ctx = np.asarray(state.context_lens)
-                    for i, r in enumerate(batch):
-                        r.context_len = int(ctx[i])
-                        r.last_token = int(tokens[i])
+                batch, state, tokens, host = stream
                 self._install_step(batch, state, tokens)
-                return {rid: toks[0] for rid, toks in out.items()}
+                return {r.req.request_id: t for r, t in zip(batch, host)}
         else:
             # promote pulls that resolved since the last step (and nudge
             # the engine while there is in-flight work to hide)
@@ -799,12 +788,9 @@ class DecodeWorker:
                 self.model, self.params, self._step_state, self._step_tokens)
         if self.inflight:
             self._step_pump(pump_budget, overlapped=True)  # hides behind the step
-        # the host waits for the chip here, and reads the tokens
-        with self.tracer.span("step.commit", track=self._track):
-            tokens = self._argmax_tokens(logits)
-            out = self._commit_step(batch, state, tokens)
+        tokens, host = self._commit(batch, logits)
         self._step_state, self._step_tokens = state, tokens
-        return out
+        return {r.req.request_id: t for r, t in zip(batch, host)}
 
     def _step_pump(self, budget: int | None, *, overlapped: bool) -> list[str]:
         """``pump`` inside a ``step.pump`` span carrying the read
@@ -834,7 +820,8 @@ class DecodeWorker:
         if self.consume == "layerwise" and self.inflight and max_new > 0:
             stream = self._streaming_step(self._round_margin(max_new), pump_budget)
         if stream is not None:
-            batch, state, tokens, out = stream
+            batch, state, tokens, host = stream
+            out = {r.req.request_id: [t] for r, t in zip(batch, host)}
             steps_left = max_new - 1
         else:
             if not self.resident:
@@ -850,13 +837,9 @@ class DecodeWorker:
             logits, state = jit_decode_step(self.model, self.params, state, tokens)
             if self.inflight:
                 self.pump(pump_budget)  # transfer hides behind the step
-            tokens = self._argmax_tokens(logits)
-            for i, r in enumerate(batch):
-                out[r.req.request_id].append(int(tokens[i]))
-                r.req.tokens_generated += 1
-        for i, r in enumerate(batch):
-            r.context_len = int(state.context_lens[i])
-            r.last_token = int(tokens[i])
+            tokens, host = self._commit(batch, logits)
+            for r, t in zip(batch, host):
+                out[r.req.request_id].append(t)
         # park the final state in the step slot and flush it, so page
         # caches include this round's appended KV — a later round (or
         # step) over the same residents rebuilds losslessly
