@@ -6,8 +6,9 @@
 For each seed, in one process (one model, so the compiled programs are
 shared): a run of the cell (``run.Run.go``) over a short window of its
 own traffic, with the control in the comparison's place.  The control
-is the reference computed in int8 (W8A8), read as the gap, under the
-float32 reference, of the token the int8 pass puts first at each
+is the configuration's reference (the ``bench/`` module its
+``"reference"`` key names) computed in int8 (W8A8), read as the gap,
+under the float32 reference, of the token the int8 pass puts first at each
 position of the same prompts and served tokens.  The run's ``correct``
 then judges the control against the configuration's limit, and has to
 come out false.  Each line also gives the program's own widest gap on
@@ -57,8 +58,8 @@ def main(argv=None) -> int:
 
     use_compilation_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    if jax.devices()[0].platform != "tpu":
-        print("control: needs a TPU", file=sys.stderr)
+    if jax.devices()[0].platform != "tpu" or len(jax.devices()) < spec.cell["chips"]:
+        print(f"control: needs {spec.cell['chips']} TPU chip(s)", file=sys.stderr)
         return 1
     model = None
     for seed in (int(s) for s in args.seeds.split(",")):

@@ -5,6 +5,10 @@ prefill of ``n`` tokens attends causally over ``n (n + 1) / 2`` query-key
 pairs per head, a decode step over the valid context of each sequence,
 and the output projection covers the true vocabulary.  A multiply-add is
 two operations.  ``sizes`` is a configuration file's ``model`` block.
+
+These are the counts of a dense decoder, where a decode step reads every
+layer's weights; a configuration names its counts module (``"counts"``),
+and one whose step reads only part of its weights names another.
 """
 from __future__ import annotations
 
@@ -12,7 +16,7 @@ from __future__ import annotations
 def _dims(sizes: dict):
     d, h, g, hd = (sizes["d_model"], sizes["num_heads"], sizes["num_kv_heads"],
                    sizes["head_dim"])
-    mats = 3 if sizes["mlp"] == "swiglu" else 2
+    mats = 3 if sizes["mlp_type"] == "swiglu" else 2
     return d, h, g, hd, mats * sizes["d_ff"]
 
 

@@ -110,14 +110,14 @@ def _forward(params, tokens, rows, *, cfg: tuple, precision: str):
 
 
 def logits(params, sizes: dict, tokens: np.ndarray, rows: np.ndarray,
-           precision: str = "f32") -> np.ndarray:
+           precision: str = "float32") -> np.ndarray:
     """Float32 logits ``[len(rows), vocab]`` of the causal forward pass over
     ``tokens``, at positions ``rows``."""
     n = len(tokens)
     padded = np.zeros(-(-n // PAD) * PAD, np.int32)
     padded[:n] = tokens
     cfg = (sizes["num_heads"], sizes["num_kv_heads"], sizes["head_dim"],
-           sizes["vocab_size"], sizes["mlp"], float(sizes["rope_theta"]),
+           sizes["vocab_size"], sizes["mlp_type"], float(sizes["rope_theta"]),
            float(sizes["norm_eps"]))
     want = np.zeros(-(-len(rows) // ROW_PAD) * ROW_PAD, np.int32)
     want[:len(rows)] = rows

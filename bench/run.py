@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Chip benchmark: one cell of ``BENCHMARK.json`` served through
-``DisaggService`` on a TPU.
+``DisaggService`` on TPUs.
 
     python3 bench/run.py --workload yi9b.docqa --seed 7 --seconds 51 --trace 0
 
@@ -8,23 +8,37 @@ A cell names a configuration (``bench/configs/<config>.json``) and a
 traffic mix (``bench/traffic/<traffic>.json``); per-layer metrics are
 read by ``bench/metrics/<metric>.py``.  All three are found by name, so
 a later cell, configuration or metric is a new file and an entry in
-``BENCHMARK.json``.
+``BENCHMARK.json``.  The configuration's ``model`` block is passed to
+the program's ``ModelConfig`` key by key (``model_config``), and the
+configuration names the two modules of ``bench/`` that stand for the
+model in the harness: its plain reference (``"reference"``) and its
+operation and byte counts (``"counts"``); ``load_module`` states what
+each must provide.
 
-One process, one chip.  Set-up (timed as ``setup_s``): weights made on
-the device from ``--seed``, the service built, every prompt length of
-the schedule and every decode (batch, pages) shape it can reach warmed, then
-``ramp_s`` of the cell's own traffic.  Then ``--seconds`` of open-loop
-arrivals are measured, and the drain waits for the window's requests
+A cell's worker layout is ``bench/layouts/<cell>.json``, found by the
+cell's name: ``{"prefill": P, "decode": D}``, one of each without it.
+``DisaggService`` puts worker ``k`` on device ``k mod n``; the run logs
+each worker's device, and a cell of more than one chip must put every
+worker on a chip of its own and use every chip it declares.
+
+Set-up (timed as ``setup_s``): weights made on the device from
+``--seed``, the service built, every prompt length of the schedule
+warmed on every prefill worker's chip and every decode (batch, pages)
+shape it can reach on every decode worker's chip, then ``ramp_s`` of
+the cell's own traffic.  Then ``--seconds`` of open-loop arrivals are
+measured, and the drain waits for the window's requests
 while arrivals continue.  A request is timed from when it was due.
 ``--trace 1`` adds the span tracer and the JAX profiler and prints the
-per-layer metrics instead of the end-to-end ones.
+per-layer metrics instead of the end-to-end ones; the device trace is
+read over every chip of the cell.
 
 After the drain the service is dropped, and a sample of the window's
-requests (the longest among them) is replayed through the plain float32
-reference: ``correct`` holds when every served token's reference logit
-lies within the configuration's limit of the reference's best.  With
-``control=True`` (``bench/control.py``) the same comparison reads the
-control instead: the reference in int8, put in the program's place.
+requests (the longest among them) is replayed through the configuration's
+plain float32 reference: ``correct`` holds when every served token's
+reference logit lies within the configuration's limit of the reference's
+best.  With ``control=True`` (``bench/control.py``) the same comparison
+reads the control instead: the reference in int8, put in the program's
+place.
 The last line of standard output is the result as one JSON object.
 """
 from __future__ import annotations
@@ -55,6 +69,18 @@ class BenchError(RuntimeError):
     """The cell cannot be run as specified."""
 
 
+# Keys of a configuration's ``model`` block that ``ModelConfig`` lacks and
+# the harness reads itself; every other key is a ``ModelConfig`` field.
+HARNESS_KEYS = ("context_length",)
+
+DEFAULT_WORKERS = {"prefill": 1, "decode": 1}
+
+# Warm-up zeros up to this size come from the host: a device zero is one
+# compiled program per shape, and the small leaves (lengths, block
+# tables) take a shape for every (batch, pages) warmed.
+HOST_ZEROS_BYTES = 1 << 20
+
+
 @dataclasses.dataclass
 class Spec:
     cell: dict
@@ -62,10 +88,20 @@ class Spec:
     traffic: dict
     end_to_end: list
     per_layer: list
+    bench_dir: Path = BENCH  # where the configuration's modules are found
+    workers: dict = dataclasses.field(default_factory=lambda: dict(DEFAULT_WORKERS))
 
     @property
     def sizes(self) -> dict:
         return self.config["model"]
+
+    @property
+    def reference(self):
+        return load_module(self.bench_dir, self.config["reference"])
+
+    @property
+    def counts(self):
+        return load_module(self.bench_dir, self.config["counts"])
 
 
 def _json(path: Path) -> dict:
@@ -85,7 +121,74 @@ def load_spec(name: str, root: Path = ROOT) -> Spec:
     layers = [m for m in bench["per_layer"] if name in m.get("workloads", [name])]
     return Spec(cell, _json(root / cfg["file"]),
                 _json(root / "bench" / "traffic" / f"{cell['traffic']}.json"),
-                metrics, layers)
+                metrics, layers, root / "bench",
+                load_layout(root / "bench" / "layouts" / f"{name}.json"))
+
+
+def load_layout(path: Path) -> dict:
+    """A cell's prefill and decode workers: its layout file, or one of
+    each where it has none."""
+    if not path.is_file():
+        return dict(DEFAULT_WORKERS)
+    layout = _json(path)
+    if set(layout) != set(DEFAULT_WORKERS) or not all(
+            isinstance(n, int) and n >= 1 for n in layout.values()):
+        raise BenchError(f"{path.name}: a layout is a count of at least 1 for each "
+                         f"of {sorted(DEFAULT_WORKERS)}; got {layout}")
+    return layout
+
+
+def _frozen(v):
+    return tuple(_frozen(x) for x in v) if isinstance(v, list) else v
+
+
+def model_config(name: str, block: dict):
+    """The program's ``ModelConfig`` from a configuration's ``model``
+    block: every key but ``HARNESS_KEYS`` is a field of that name, lists
+    become tuples (the config is frozen and hashed).  A key that is no
+    field is refused by name, never dropped."""
+    from repro.models.config import ModelConfig
+
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    kwargs = {"name": name}
+    for key, value in block.items():
+        if key in HARNESS_KEYS:
+            continue
+        if key not in fields:
+            raise BenchError(f"model key {key!r} is not a ModelConfig field "
+                             f"(nor one of the harness's own: {HARNESS_KEYS})")
+        kwargs[key] = _frozen(value)
+    return ModelConfig(**kwargs)
+
+
+_MODULES: dict[Path, object] = {}
+
+
+def load_module(bench_dir: Path, name: str):
+    """``<bench_dir>/<name>.py``, loaded once per process (so its jitted
+    functions keep their caches).  A configuration names two:
+
+    - its reference, ``logits(params, sizes, seq, rows, precision="float32")``:
+      float32 logits ``[len(rows), vocab]`` of the plain causal forward pass
+      over the token ids ``seq``, at positions ``rows``, from the served
+      parameter tree ``params`` and the configuration's ``model`` block
+      ``sizes``; ``precision="int8"`` is the control, the same pass a step
+      below the served precision.  It imports nothing of the program, and
+      may import helpers from ``reference.py``.
+    - its counts, from shapes alone: ``prefill_flops(sizes, n)`` for one
+      prompt of ``n`` tokens, ``decode_flops(sizes, contexts)`` and
+      ``decode_bytes(sizes, contexts)`` for one decode step whose sequences
+      attend over ``contexts`` keys each, and ``roofline_s(flops, nbytes,
+      peak)``, the least time the chip could take."""
+    path = (bench_dir / f"{name}.py").resolve()
+    if path not in _MODULES:
+        if not path.is_file():
+            raise BenchError(f"no module {name!r} in {bench_dir}")
+        spec = importlib.util.spec_from_file_location(f"bench_module_{name}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _MODULES[path] = mod
+    return _MODULES[path]
 
 
 def pct(values, q: float) -> float | None:
@@ -112,7 +215,6 @@ class Run:
     def build(self) -> None:
         import jax
 
-        from repro.models.config import ModelConfig
         from repro.models.registry import build_model
         from repro.obs.trace import Tracer
         from repro.serving.disagg import DisaggService
@@ -121,21 +223,18 @@ class Run:
 
         s = self.spec.sizes
         if self.model is None:
-            self.model = build_model(ModelConfig(
-                name=self.spec.cell["config"], family="dense",
-                num_layers=s["num_layers"], d_model=s["d_model"],
-                vocab_size=s["vocab_size"], num_heads=s["num_heads"],
-                num_kv_heads=s["num_kv_heads"], d_ff=s["d_ff"],
-                head_dim=s["head_dim"], mlp_type=s["mlp"],
-                rope_theta=float(s["rope_theta"]), norm_eps=float(s["norm_eps"])))
+            self.model = build_model(model_config(self.spec.cell["config"], s))
         self.cfg = self.model.cfg
         shapes = jax.eval_shape(self.model.init_params, jax.random.PRNGKey(0))
         self.params = weights.make(shapes, self.seed)
         jax.block_until_ready(self.params)
         self.tracer = Tracer(clock=time.perf_counter) if self.trace else None
-        self.svc = DisaggService(self.model, self.params, n_prefill=1, n_decode=1,
+        w = self.spec.workers
+        self.svc = DisaggService(self.model, self.params, n_prefill=w["prefill"],
+                                 n_decode=w["decode"],
                                  num_blocks=self.spec.config["num_blocks"],
                                  tracer=self.tracer)
+        self.devices = self.layout()
         self.sched = workload.schedule(self.spec.traffic, self.seconds)
         ctx = s["context_length"]
         for a in self.sched:
@@ -146,13 +245,30 @@ class Run:
         self.prompts = [rng.integers(0, s["vocab_size"], a.prompt_len).astype(np.int32)
                         for a in self.sched]
 
+    def layout(self) -> list:
+        """The chips the workers compute on, in device order; refuses a
+        layout that does not match the cell's ``chips``."""
+        where = {wid: w.device for pool in (self.svc.prefills, self.svc.decodes)
+                 for wid, w in pool.items()}
+        self.placement = {wid: d.id for wid, d in where.items()}
+        self.log("workers: " + ", ".join(f"{wid} on {d}" for wid, d in where.items()))
+        devices = sorted(set(where.values()), key=lambda d: d.id)
+        chips = self.spec.cell["chips"]
+        if chips > 1 and len(devices) < len(where):
+            raise BenchError(f"a cell of {chips} chips puts two of its "
+                             f"{len(where)} workers on one chip")
+        if len(devices) != chips:
+            raise BenchError(f"the workers use {len(devices)} chip(s); the cell "
+                             f"declares {chips}")
+        return devices
+
     def decode_shapes(self) -> list[tuple[int, int]]:
-        """Every (batch, pages) state the decode worker can build for this
+        """Every (batch, pages) state a decode worker can build for this
         schedule: pages are the largest member's valid pages plus the
         worker's margin, and a member spans from its prompt's blocks to
         its last decode input (``prompt + max_new - 1`` positions)."""
         bs = self.model.BLOCK_SIZE
-        margin = next(iter(self.svc.decodes.values())).step_margin_blocks
+        margin = self._margin()
         pages = set()
         for p, o in workload.all_sizes(self.spec.traffic, self.seconds):
             lo, hi = -(-p // bs), -(-(p + o - 1) // bs)
@@ -160,43 +276,56 @@ class Run:
         bmax = self.spec.traffic["warm_batch_max"]
         return [(b, n) for b in range(1, bmax + 1) for n in sorted(pages)]
 
+    def _margin(self) -> int:
+        return next(iter(self.svc.decodes.values())).step_margin_blocks
+
     def warm(self) -> None:
         """Compile (or load from the persistent cache) every program the
-        window can call, the way the engine calls it."""
+        window can call, the way the engine calls it, on every worker's
+        chip: a program compiled for one chip is not the one another runs.
+        Decode states are the model's own ``decode_state_shape``, zeroed:
+        a leaf of up to ``HOST_ZEROS_BYTES`` is put from the host (no
+        program), a larger one is made on the chip once per shape and
+        type, so k and v share one buffer."""
         import jax
         import jax.numpy as jnp
 
-        from repro.models.transformer import DecodeState
         from repro.serving.engine import jit_decode_step, jit_prefill
 
         V = self.cfg.vocab_size
-        pw = next(iter(self.svc.prefills.values()))
-        dw = next(iter(self.svc.decodes.values()))
         lengths = sorted({p for p, _ in workload.all_sizes(self.spec.traffic, self.seconds)})
-        for n in lengths:
-            logits, st = jit_prefill(self.model, pw.params,
-                                     jax.device_put(np.zeros((1, n), np.int32), pw.device))
-            np.asarray(st.k_pages[:, 0])
-            np.asarray(st.v_pages[:, 0])
-            int(jnp.argmax(logits[0, :V]))
+        for pw in _on_distinct_devices(self.svc.prefills):
+            for n in lengths:
+                logits, st = jit_prefill(self.model, pw.params,
+                                         jax.device_put(np.zeros((1, n), np.int32), pw.device))
+                for leaf in jax.tree.leaves(st):  # each sequence's pages, as parked
+                    np.asarray(leaf[:, 0] if leaf.ndim > 2 else leaf)
+                int(jnp.argmax(logits[0, :V]))
         shapes = self.decode_shapes()
-        c, dev = self.cfg, dw.device
-        for b, per in shapes:
-            kv = jnp.zeros((c.num_layers, b, per, self.model.BLOCK_SIZE,
-                            c.num_kv_heads, c.head_dim), jnp.bfloat16, device=dev)
-            st = DecodeState(
-                context_lens=jax.device_put(np.full(b, per - 3, np.int32), dev),
-                k_pages=kv, v_pages=kv,
-                block_tables=jax.device_put(
-                    np.broadcast_to(np.arange(per, dtype=np.int32), (b, per)), dev))
-            logits, new = jit_decode_step(self.model, dw.params, st,
-                                          jax.device_put(np.zeros(b, np.int32), dev))
-            toks = jnp.argmax(logits[:, :V].astype(jnp.float32), axis=-1).astype(jnp.int32)
-            np.asarray(new.context_lens)
-            [int(toks[i]) for i in range(b)]
-            del kv, st, new
+        bs, margin = self.model.BLOCK_SIZE, self._margin()
+        for dw in _on_distinct_devices(self.svc.decodes):
+            dev = dw.device
+            for b, per in shapes:
+                zeros = {}
+
+                def zero(x):
+                    key = (x.shape, x.dtype)
+                    if key not in zeros:
+                        small = np.prod(x.shape) * x.dtype.itemsize <= HOST_ZEROS_BYTES
+                        zeros[key] = (jax.device_put(np.zeros(x.shape, x.dtype), dev)
+                                      if small else jnp.zeros(x.shape, x.dtype, device=dev))
+                    return zeros[key]
+
+                st = jax.tree.map(zero, self.model.decode_state_shape(
+                    b, (per - margin) * bs, margin=margin))
+                logits, new = jit_decode_step(self.model, dw.params, st,
+                                              jax.device_put(np.zeros(b, np.int32), dev))
+                toks = jnp.argmax(logits[:, :V].astype(jnp.float32), axis=-1).astype(jnp.int32)
+                np.asarray(toks).tolist()
+                del st, new, zeros
         self.log(f"warmed {len(lengths)} prompt lengths and {len(shapes)} decode "
-                 f"shapes (batch <= {self.spec.traffic['warm_batch_max']})")
+                 f"shapes (batch <= {self.spec.traffic['warm_batch_max']}) on each "
+                 f"worker's chip")
 
     # -------------------------------------------------------- driving
     def _submit(self, i: int) -> None:
@@ -224,14 +353,18 @@ class Run:
                 self._record(report)
 
     def _record(self, report) -> None:
-        """Shapes of the programs this tick ran, for the FLOP counts."""
+        """Shapes of the programs this tick ran, for the FLOP counts: each
+        prefill, and each decode worker's step (one per worker with
+        tokens this tick)."""
         for rid in report.dispatched:
             self.calls["prefill"].append(self.sched[self.by_rid[rid]].prompt_len)
-        if report.tokens:
-            ctx = []
-            for rid in report.tokens:
-                i = self.by_rid[rid]
-                ctx.append(self.sched[i].prompt_len + len(self.recs[i]["handle"].tokens) - 1)
+        steps: dict[str, list[int]] = {}
+        for rid in report.tokens:
+            i = self.by_rid[rid]
+            h = self.recs[i]["handle"]
+            steps.setdefault(h.request.decode_worker, []).append(
+                self.sched[i].prompt_len + len(h.tokens) - 1)
+        for ctx in steps.values():
             self.calls["decode"].append(ctx)
             self.max_batch = max(self.max_batch, len(ctx))
 
@@ -281,7 +414,7 @@ class Run:
         self.window = window
         self.log("programs added inside the window and drain: " + ", ".join(
             f"{k} {v}" for k, v in self.window_compiles.items()))
-        self.log(f"largest decode batch in the window and drain: {self.max_batch} "
+        self.log(f"largest decode batch of a worker in the window and drain: {self.max_batch} "
                  f"(warmed up to {self.spec.traffic['warm_batch_max']}); "
                  f"{len(self.calls['decode'])} decode steps, "
                  f"{len(self.calls['prefill'])} prefills")
@@ -324,16 +457,18 @@ class Run:
                   and _mean_gap(r["token_times"]) <= slo["tbt_mean_s"])
         self.log(f"slo attainment: {met}/{len(reqs)} = {met / max(1, len(reqs))} "
                  f"(ttft <= {slo['ttft_s']} s and mean tbt <= {slo['tbt_mean_s']} s)")
+        self.tails = {"req_latency_p90_s": pct(lat, 90), "ttft_p90_s": pct(ttft, 90),
+                      "slo_attainment": met / max(1, len(reqs))}
         return {"tbt_p50_ms": 1e3 * pct(gaps, 50),
                 "output_tok_per_s": out_tokens / self.seconds,
                 "setup_s": self.setup_s}
 
     def memory_peak(self) -> int | None:
-        import jax
-
-        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-                 for d in jax.devices()[: self.spec.cell["chips"]]]
-        peaks = [p for p in peaks if p is not None]
+        """Peak bytes in use on the fullest of the workers' chips."""
+        peaks = {d.id: (d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in self.devices}
+        self.log(f"peak bytes in use by chip: {peaks}")
+        peaks = [p for p in peaks.values() if p is not None]
         return max(peaks) if peaks else None
 
     def check(self, reqs: list[dict]) -> dict:
@@ -364,8 +499,7 @@ class Run:
         of the served token, with what explains it (the reference's median
         top-2 margin, the distinct tokens served).  With ``control``, also
         the widest gap of the token the int8 reference puts first."""
-        import reference
-
+        reference = self.spec.reference
         p = self.prompts[i]
         seq = np.concatenate([p, served[:-1]]).astype(np.int32)
         rows = np.arange(len(p) - 1, len(p) - 1 + len(served))
@@ -382,7 +516,6 @@ class Run:
         return out
 
     def per_layer(self, reqs: list[dict]) -> dict[str, float]:
-        import flops
         import peaks
 
         import jax
@@ -391,7 +524,7 @@ class Run:
             requests=reqs, tracer=self.tracer, calls=self.calls,
             compiles=self.window_compiles, sizes=self.spec.sizes,
             peak=peaks.peaks(jax.devices()[0].device_kind) if self.require_tpu else None,
-            flops=flops, trace=self.device_trace,
+            flops=self.spec.counts, trace=self.device_trace,
             t0=self.trace_t0, t1=self.trace_t1)
         out = {}
         for m in self.spec.per_layer:
@@ -402,7 +535,11 @@ class Run:
 
     def reduce_trace(self) -> None:
         """Device busy time, per-program device time and idle gaps of the
-        traced window, from the profiler's file."""
+        traced window, from the profiler's file, over every chip the
+        workers use: busy time is the mean over the chips, program times
+        are summed over them, and the top operations and idle gaps are
+        taken from all of them, each named with its chip (``d2:``) where
+        the cell has more than one."""
         import devtrace
 
         prof = devtrace.read_profile(devtrace.latest_xplane(self.trace_dir))
@@ -411,30 +548,40 @@ class Run:
             raise BenchError("the profile has no bench.clock mark")
         off = mark - int(self.mark_t * 1e9)
         t0, t1 = int(self.trace_t0 * 1e9) + off, int(self.trace_t1 * 1e9) + off
-        chips = self.spec.cell["chips"]
-        devs = sorted(prof["devices"].items())[:chips]
-        busy = [devtrace.union_ns(devtrace.clip(d["ops"], t0, t1)) / 1e9 for _, d in devs]
-        ops0, mods0 = devs[0][1]["ops"], devs[0][1]["modules"]
+        planes = {f"/device:TPU:{d.id}": d.id for d in self.devices}
+        missing = sorted(set(planes) - set(prof["devices"]))
+        if missing:
+            raise BenchError(f"the profile has no plane {missing}; planes: "
+                             f"{sorted(prof['devices'])}")
         host = [(int(s.t0 * 1e9) + off, int(s.t1 * 1e9) + off, s.name)
                 for s in (self.tracer.spans if self.tracer else []) if s.t1 is not None]
         host += [(int(self.recs[i]["submitted"] * 1e9) + off,
                   int(self.recs[i]["submitted"] * 1e9) + off + 1, "submit")
                  for i in self.recs]
-        idle = devtrace.gaps(ops0, t0, t1)
+        busy, prefill_s, decode_s, top, idle, seen = [], 0.0, 0.0, [], [], set()
+        for plane, dev_id in planes.items():
+            ops = devtrace.clip(prof["devices"][plane]["ops"], t0, t1)
+            all_mods = prof["devices"][plane]["modules"]
+            mods = devtrace.clip(all_mods, t0, t1)
+            tag = f"d{dev_id}:" if len(planes) > 1 else ""
+            busy.append(devtrace.union_ns(ops) / 1e9)
+            prefill_s += devtrace.module_seconds(mods, "jit_prefill")
+            decode_s += devtrace.module_seconds(mods, "jit_decode_step")
+            top += [[tag + n, v] for n, v in devtrace.top_ops(ops, modules=all_mods)]
+            idle += [[tag + n, v] for n, v in devtrace.label_gaps(
+                devtrace.gaps(ops, t0, t1), host)]
+            seen |= {tag + n.split("(")[0] for _, _, n in mods}
         shutil.rmtree(self.trace_dir, ignore_errors=True)
         self.device_trace = {
             "busy_s": sum(busy) / len(busy), "window_s": (t1 - t0) / 1e9,
-            "prefill_s": devtrace.module_seconds(devtrace.clip(mods0, t0, t1), "jit_prefill"),
-            "decode_s": devtrace.module_seconds(devtrace.clip(mods0, t0, t1), "jit_decode_step"),
-            "breakdown": {"device_ops": devtrace.top_ops(devtrace.clip(ops0, t0, t1),
-                                                          modules=mods0),
-                          "idle_gaps": devtrace.label_gaps(idle, host)},
+            "prefill_s": prefill_s, "decode_s": decode_s,
+            "breakdown": {"device_ops": sorted(top, key=lambda e: -e[1])[:10],
+                          "idle_gaps": sorted(idle, key=lambda e: -e[1])[:10]},
         }
         self.log(f"trace: busy {self.device_trace['busy_s']} s of "
-                 f"{self.device_trace['window_s']} s; prefill programs "
-                 f"{self.device_trace['prefill_s']} s, decode programs "
-                 f"{self.device_trace['decode_s']} s; modules seen: "
-                 f"{sorted({n.split('(')[0] for _, _, n in mods0})[:8]}")
+                 f"{self.device_trace['window_s']} s (by chip: {busy}); prefill "
+                 f"programs {prefill_s} s, decode programs {decode_s} s; modules "
+                 f"seen: {sorted(seen)[:8]}")
 
     def go(self) -> dict:
         import jax
@@ -489,6 +636,12 @@ class Run:
         for name, c in result["check"].items():
             print(f"[check] {name} {c['value']} limit {c['limit']}", file=sys.stderr)
         return result
+
+
+def _on_distinct_devices(workers: dict) -> list:
+    """One worker per device among ``workers`` (workers that share a
+    device share its compiled programs)."""
+    return list({w.device: w for w in workers.values()}.values())
 
 
 def _programs(jitted) -> int:

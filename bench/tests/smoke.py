@@ -15,12 +15,14 @@ for p in (str(BENCH), str(BENCH.parent / "src")):
 import run  # noqa: E402
 
 MODELS = {
-    "yi": {"num_layers": 2, "d_model": 64, "num_heads": 8, "num_kv_heads": 1,
-           "head_dim": 8, "d_ff": 192, "vocab_size": 512, "mlp": "swiglu",
-           "rope_theta": 10000, "norm_eps": 1e-05, "context_length": 512},
-    "granite": {"num_layers": 2, "d_model": 64, "num_heads": 4, "num_kv_heads": 1,
-                "head_dim": 16, "d_ff": 256, "vocab_size": 512, "mlp": "gelu",
-                "rope_theta": 10000, "norm_eps": 1e-05, "context_length": 512},
+    "yi": {"family": "dense", "num_layers": 2, "d_model": 64, "num_heads": 8,
+           "num_kv_heads": 1, "head_dim": 8, "d_ff": 192, "vocab_size": 512,
+           "mlp_type": "swiglu", "rope_theta": 10000.0, "norm_eps": 1e-05,
+           "context_length": 512},
+    "granite": {"family": "dense", "num_layers": 2, "d_model": 64, "num_heads": 4,
+                "num_kv_heads": 1, "head_dim": 16, "d_ff": 256, "vocab_size": 512,
+                "mlp_type": "gelu", "rope_theta": 10000.0, "norm_eps": 1e-05,
+                "context_length": 512},
 }
 
 TRAFFIC = {
@@ -34,7 +36,8 @@ TRAFFIC = {
 
 def spec(arch: str = "yi", limit: float | None = 0.05) -> run.Spec:
     bench = run._json(run.ROOT / "BENCHMARK.json")
-    config = {"model": MODELS[arch], "num_blocks": 64,
+    config = {"model": copy.deepcopy(MODELS[arch]), "reference": "reference",
+              "counts": "flops", "num_blocks": 64,
               "slo": {"ttft_s": 1.0, "tbt_mean_s": 0.1},
               "correct": {"max_logit_gap": limit}}
     cell = {"name": f"smoke.{arch}", "config": arch, "traffic": "smoke", "chips": 1}

@@ -38,7 +38,8 @@ def test_yi_9b_24l():
 # ibm-granite/granite-34b-code-base): the GELU-MLP, one-KV-head shape that
 # the counts also cover.
 GRANITE_34B_11L = {"num_layers": 11, "d_model": 6144, "num_heads": 48, "num_kv_heads": 1,
-                   "head_dim": 128, "d_ff": 24576, "vocab_size": 49152, "mlp": "gelu"}
+                   "head_dim": 128, "d_ff": 24576, "vocab_size": 49152,
+                   "mlp_type": "gelu"}
 
 
 def test_granite_34b_11l():

@@ -7,8 +7,8 @@ import pytest
 
 import smoke  # noqa: F401  (puts bench/ and src/ on the path)
 import reference
+import run
 import weights
-from repro.models.config import ModelConfig
 from repro.models.registry import build_model
 from repro.serving.engine import jit_decode_step, jit_prefill
 
@@ -23,11 +23,7 @@ TOL_OF_STD = 0.1
 
 def _model(arch):
     s = smoke.MODELS[arch]
-    cfg = ModelConfig(name=arch, family="dense", num_layers=s["num_layers"],
-                      d_model=s["d_model"], vocab_size=s["vocab_size"],
-                      num_heads=s["num_heads"], num_kv_heads=s["num_kv_heads"],
-                      d_ff=s["d_ff"], head_dim=s["head_dim"], mlp_type=s["mlp"])
-    model = build_model(cfg)
+    model = build_model(run.model_config(arch, s))
     params = weights.make(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)), 3)
     return s, model, params
 

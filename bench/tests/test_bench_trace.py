@@ -31,7 +31,7 @@ def test_idle_gaps_are_named_by_the_innermost_host_span():
 
 def _ctx(trace, calls):
     sizes = {"num_layers": 2, "d_model": 8, "num_heads": 2, "num_kv_heads": 1,
-             "head_dim": 4, "d_ff": 16, "vocab_size": 32, "mlp": "swiglu"}
+             "head_dim": 4, "d_ff": 16, "vocab_size": 32, "mlp_type": "swiglu"}
     return run.LayerContext(requests=[], tracer=None, calls=calls, compiles={},
                             sizes=sizes, peak=peaks.peaks("TPU v5 lite"),
                             flops=flops, trace=trace, t0=0.0, t1=1.0), sizes
